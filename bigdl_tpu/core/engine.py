@@ -12,6 +12,7 @@ default sharding axes.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 from typing import Optional, Sequence, Tuple
 
@@ -22,6 +23,30 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bigdl_tpu.core.config import EngineConfig
 
 log = logging.getLogger("bigdl_tpu")
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Keep compiled programs across processes; returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: when it
+    is set JAX reads it and nothing is set in code. Otherwise the cache
+    lives at ``<checkout>/.jax_cache`` — a FIXED path (a directory that
+    moves never hits). The thresholds drop to zero so the serving
+    engine's small steps are kept too, not only the minute-long train
+    step. A process held to the CPU (``JAX_PLATFORMS=cpu``) gets no cache
+    and ``None``: XLA:CPU compiles in seconds and reloads cached code with
+    a page of machine-feature warnings per program."""
+    if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
+        return None
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 class Engine:
@@ -55,6 +80,7 @@ class Engine:
     # ---- init / singleton ----
     @classmethod
     def init(cls, config: Optional[EngineConfig] = None) -> "Engine":
+        enable_compile_cache()
         with cls._lock:
             if cls._instance is None or config is not None:
                 cls._instance = Engine(config)

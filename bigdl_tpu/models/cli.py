@@ -29,9 +29,11 @@ def make_parser(name: str, batch_size: int, max_epoch: int,
 def fit(opt, args, checkpoint_trigger=None):
     """Wire the shared end/checkpoint policy and run (the tail every
     Train.scala repeats)."""
+    from bigdl_tpu.core.engine import enable_compile_cache
     from bigdl_tpu.optim import Trigger
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     opt.set_end_when(Trigger.max_iteration(args.maxIteration)
                      if args.maxIteration else Trigger.max_epoch(args.maxEpoch))
     if args.checkpoint:

@@ -263,7 +263,10 @@ def main(argv=None):
     if args.dataset == "imagenet":
         model = build_imagenet(args.depth if args.depth in IMAGENET_CFG else 50,
                                1000, data_format=args.dataFormat)
-        x, y = _synthetic_images(64, (3, 224, 224), 1000, seed=1)
+        # at least one full batch: the drop-last training stream refuses a
+        # batch larger than the dataset
+        x, y = _synthetic_images(max(64, args.batchSize), (3, 224, 224),
+                                 1000, seed=1)
         if args.dataFormat == "NHWC":
             x = np.ascontiguousarray(np.transpose(x, (0, 2, 3, 1)))
     else:

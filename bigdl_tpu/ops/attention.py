@@ -11,6 +11,8 @@ einsum path elsewhere.
 
 from __future__ import annotations
 
+import collections
+import logging
 from typing import Optional
 
 import jax
@@ -19,6 +21,36 @@ import jax.numpy as jnp
 from bigdl_tpu.ops import flash_attention as _fa
 
 _NEG = -1e9
+_log = logging.getLogger(__name__)
+
+# Auto-selection on a TPU that could NOT take the Pallas kernel, counted
+# per reason at trace time (``chip_smoke.py`` prints it): the XLA path is
+# correct but is not the kernel, and nobody should have to guess which ran.
+kernel_fallbacks: collections.Counter = collections.Counter()
+
+
+def _fallback(reason: str) -> bool:
+    if not kernel_fallbacks[reason]:
+        _log.warning("attention: XLA path on TPU (%s)", reason)
+    kernel_fallbacks[reason] += 1
+    return False
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def _kernel_platform() -> bool:
+    """Auto-selection's first question: a TPU, and no active mesh. A
+    Mosaic call has no GSPMD partitioning rule ("Mosaic kernels cannot be
+    automatically partitioned"), so under ``parallel.mesh.use_mesh`` XLA
+    partitions the einsum path and inserts the collectives instead."""
+    if not _on_tpu():
+        return False
+    from bigdl_tpu.parallel.mesh import current_mesh  # import cycle via tp
+
+    return current_mesh() is None or _fallback(
+        "active mesh: a Mosaic call cannot be partitioned")
 
 
 def attention_bias_from_padding(padding_mask: jax.Array) -> jax.Array:
@@ -39,11 +71,13 @@ def causal_bias(length: int) -> jax.Array:
 
 def _flash_ok(q, k) -> bool:
     if q.shape[-1] > 256:
-        return False
+        return _fallback(f"flash: head_dim {q.shape[-1]} > 256")
     sq, sk = q.shape[-2], k.shape[-2]
     bq = min(128, sq)
     bk = min(128, sk)
-    return sq % bq == 0 and sk % bk == 0
+    if sq % bq or sk % bk:
+        return _fallback(f"flash: seq lens ({sq}, {sk}) not in 128-blocks")
+    return True
 
 
 def paged_attention(
@@ -57,6 +91,7 @@ def paged_attention(
     use_kernel: Optional[bool] = None,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Decode-step attention over a paged (block-table) KV cache.
 
@@ -70,15 +105,18 @@ def paged_attention(
     the serving tier swap lanes for pages without changing one token.
     Int8 pools pass their per-token fp32 scale pools
     (``k_scales``/``v_scales``, shape (num_pages, page_size)): both
-    paths dequantize on gather.
+    paths dequantize on gather. ``interpret`` is for tests only: the
+    auto path never interprets, and ``use_kernel=True`` off a TPU lowers
+    the real kernel (and fails where there is no TPU compiler).
     """
-    platform = jax.devices()[0].platform
     if use_kernel is None:
-        use_kernel = platform == "tpu" and q.shape[-1] <= 256
+        use_kernel = _kernel_platform() and (
+            q.shape[-1] <= 256
+            or _fallback(f"paged: head_dim {q.shape[-1]} > 256"))
     if use_kernel:
         return _fa.paged_flash_attention(
             q, k_pages, v_pages, page_map, positions, sm_scale,
-            interpret=(platform != "tpu"),
+            interpret=interpret,
             k_scales=k_scales, v_scales=v_scales,
         )
     return _fa.paged_attention_reference(
@@ -97,6 +135,7 @@ def dot_product_attention(
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Attention over (B, H, S, D) tensors.
 
@@ -104,17 +143,16 @@ def dot_product_attention(
     and there is no attention dropout (dropout inside the probability matrix
     defeats the fused formulation; the reference's attentionDropout is only
     active in training, where the XLA path is used instead).
+    ``interpret`` is for tests only; the auto path never interprets.
     """
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    platform = jax.devices()[0].platform
     if use_flash is None:
-        use_flash = platform == "tpu" and dropout_rate == 0.0 and _flash_ok(q, k)
+        use_flash = (dropout_rate == 0.0 and _kernel_platform()
+                     and _flash_ok(q, k))
 
     if use_flash and dropout_rate == 0.0:
         return _fa.flash_attention(
-            q, k, v, bias, scale, causal,
-            interpret=(platform != "tpu"),
-        )
+            q, k, v, bias, scale, causal, interpret=interpret)
 
     if dropout_rate > 0.0 and dropout_rng is None:
         raise ValueError("attention dropout needs dropout_rng")

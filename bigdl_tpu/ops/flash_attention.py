@@ -253,10 +253,10 @@ def _paged_kernel(pm_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
         v = v_ref[0, 0].astype(jnp.float32)
         if ks_ref is not None:
-            # int8 pages: per-token scales ride in their own (1, ps)
+            # int8 pages: per-token scales ride in their own (1, 1, ps)
             # block DMA'd through the same scalar-prefetched page id
-            k = k * ks_ref[0][:, None]
-            v = v * vs_ref[0][:, None]
+            k = k * ks_ref[0, 0][:, None]
+            v = v * vs_ref[0, 0][:, None]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -296,8 +296,13 @@ def paged_flash_attention(q, k_pages, v_pages, page_map, positions,
     physical page directly. Same signature/semantics as
     :func:`paged_attention_reference` (q: (S, H, D) -> (S, H, D));
     int8 pools pass their per-token scale pools, each streamed as a
-    (1, page_size) block through the same prefetched page id and applied
-    before the score matmul."""
+    (1, 1, page_size) block through the same prefetched page id and
+    applied before the score matmul.
+
+    The TPU lowering wants the last two dims of every block to be whole
+    array dims (or (8k, 128k) tiles), so ``q``/the output get a unit
+    second-minor axis ((S, H, 1, D), blocks (1, 1, 1, D)) and the scale
+    pools likewise ((num_pages, 1, page_size)); both reshapes are free."""
     n_slots, heads, d = q.shape
     n_phys, _, page_size, _ = k_pages.shape
     ppn = page_map.shape[1]
@@ -305,21 +310,22 @@ def paged_flash_attention(q, k_pages, v_pages, page_map, positions,
     int8_kv = k_scales is not None
 
     in_specs = [
-        pl.BlockSpec((1, 1, d), lambda s, h, p, pm, pos: (s, h, 0)),
+        pl.BlockSpec((1, 1, 1, d), lambda s, h, p, pm, pos: (s, h, 0, 0)),
         pl.BlockSpec((1, 1, page_size, d),
                      lambda s, h, p, pm, pos: (pm[s, p], h, 0, 0)),
         pl.BlockSpec((1, 1, page_size, d),
                      lambda s, h, p, pm, pos: (pm[s, p], h, 0, 0)),
     ]
-    args = [q, k_pages, v_pages]
+    args = [q[:, :, None, :], k_pages, v_pages]
     if int8_kv:
         in_specs += [
-            pl.BlockSpec((1, page_size),
-                         lambda s, h, p, pm, pos: (pm[s, p], 0)),
-            pl.BlockSpec((1, page_size),
-                         lambda s, h, p, pm, pos: (pm[s, p], 0)),
+            pl.BlockSpec((1, 1, page_size),
+                         lambda s, h, p, pm, pos: (pm[s, p], 0, 0)),
+            pl.BlockSpec((1, 1, page_size),
+                         lambda s, h, p, pm, pos: (pm[s, p], 0, 0)),
         ]
-        args += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
+        args += [k_scales.astype(jnp.float32)[:, None, :],
+                 v_scales.astype(jnp.float32)[:, None, :]]
         kernel = functools.partial(
             _paged_kernel, sm_scale=scale, page_size=page_size, n_pages=ppn)
     else:
@@ -332,7 +338,8 @@ def paged_flash_attention(q, k_pages, v_pages, page_map, positions,
         num_scalar_prefetch=2,
         grid=(n_slots, heads, ppn),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda s, h, p, pm, pos: (s, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, d),
+                               lambda s, h, p, pm, pos: (s, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, _MIN_LANE), jnp.float32),
@@ -340,12 +347,13 @@ def paged_flash_attention(q, k_pages, v_pages, page_map, positions,
         ],
     )
     out_dtype = jnp.float32 if int8_kv else q.dtype
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, heads, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, d), out_dtype),
         interpret=interpret,
     )(page_map.astype(jnp.int32), positions.astype(jnp.int32), *args)
+    return out[:, :, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))

@@ -218,19 +218,6 @@ def serving_meshes(n_replicas: int, tp: int = 1, *, axis: str = "tp",
             for i in range(n_replicas)]
 
 
-def mark_varying(t, axis_name):
-    """Cast ``t`` to device-varying over ``axis_name`` (shard_map type
-    system). ``pcast`` is the current API; ``pvary`` its deprecated
-    ancestor; very old jax has neither and tracks no varying types, so
-    identity is correct. Shared by the ring-attention and pipeline
-    collectives."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(t, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(t, (axis_name,))
-    return t
-
-
 def ring_perm(n: int):
     """Neighbor permutation for ``lax.ppermute`` ring shifts."""
     return [(i, (i + 1) % n) for i in range(n)]

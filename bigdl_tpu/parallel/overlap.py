@@ -18,8 +18,7 @@ collectives therefore sit in the middle of the backward graph carrying
 only their true dependencies; the scheduler is free to run the rest of
 the backward while the wire is busy, instead of the auto-sharding
 baseline where the AllReduceCombiner rolls every gradient into one
-all-reduce AFTER the full backward (measured in round 3/4:
-``perf/artifacts/overlap_hlo_summary.txt``). ``perf/overlap_sched.py``
+all-reduce AFTER the full backward. ``perf/overlap_sched.py``
 AOT-compiles both flavors for a real v5e topology and records the
 collective placement as the round-5 artifact.
 
@@ -41,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from bigdl_tpu.parallel._compat import shard_map
 
 
 # --------------------------------------------------------- bucketing ----
@@ -335,7 +333,7 @@ def make_ddp_overlap_step(model, criterion, method, mesh: Mesh,
         def _step(params, mstate, ostate, x, y, it):
             return _core(params, mstate, ostate, x, y, it, None)
         in_specs = (repl, repl, repl, shard, shard, repl)
-    return shard_map(
+    return jax.shard_map(
         _step, mesh=mesh,
         in_specs=in_specs,
         out_specs=(repl, repl, repl, repl),
@@ -453,7 +451,7 @@ def make_zero1_overlap_step(model, criterion, method, mesh: Mesh,
         return new_p, new_ms, new_ostate, lax.pmean(loss, axis)
 
     repl, shard = P(), P(axis)
-    return shard_map(
+    return jax.shard_map(
         _step, mesh=mesh,
         in_specs=(repl, repl, state_spec, shard, shard, repl),
         out_specs=(repl, repl, state_spec, repl),

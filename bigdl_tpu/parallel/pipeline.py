@@ -24,10 +24,9 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from bigdl_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bigdl_tpu.parallel.mesh import mark_varying, ring_perm
+from bigdl_tpu.parallel.mesh import ring_perm
 
 
 def _stage_body(stage_fn, n_stages, n_micro, axis_name, params, xs):
@@ -42,9 +41,8 @@ def _stage_body(stage_fn, n_stages, n_micro, axis_name, params, xs):
     micro_shape = xs.shape[1:]
     out0 = jnp.zeros((n_micro,) + micro_shape, xs.dtype)
     recv0 = jnp.zeros(micro_shape, xs.dtype)
-    out0 = mark_varying(out0, axis_name)
-    recv0 = mark_varying(recv0, axis_name)
-    xs = mark_varying(xs, axis_name)
+    out0, recv0, xs = (lax.pcast(t, axis_name, to="varying")
+                       for t in (out0, recv0, xs))
 
     def tick(carry, t):
         recv, outs = carry
@@ -100,9 +98,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         squeezed = jax.tree_util.tree_map(lambda a: a[0], params)
         return body(squeezed, xs_local)
 
-    fn = shard_map(per_chip, mesh=mesh,
-                   in_specs=(param_specs, P()),
-                   out_specs=P())
+    fn = jax.shard_map(per_chip, mesh=mesh,
+                       in_specs=(param_specs, P()),
+                       out_specs=P(), check_vma=False)
     ys = fn(stacked_params, xs)
     return ys.reshape((b,) + ys.shape[2:])
 
@@ -131,11 +129,10 @@ def _hetero_body(stage_fns, n_stages, n_micro, axis_name,
     perm = ring_perm(n)
 
     micro_shape = xs.shape[1:]
-    out0 = mark_varying(jnp.zeros((n_micro,) + micro_shape, xs.dtype), axis_name)
-    recv0 = mark_varying(jnp.zeros(micro_shape, xs.dtype), axis_name)
-    xs = mark_varying(xs, axis_name)
-    states = jax.tree_util.tree_map(
-        lambda a: mark_varying(a, axis_name), states)
+    out0, recv0, xs, states = jax.tree_util.tree_map(
+        lambda a: lax.pcast(a, axis_name, to="varying"),
+        (jnp.zeros((n_micro,) + micro_shape, xs.dtype),
+         jnp.zeros(micro_shape, xs.dtype), xs, states))
 
     def branches(i):
         def br(x, st, key):
@@ -277,10 +274,10 @@ class HeteroPipeline:
             return body(params, states, xs_local, rng_in, training)
 
         repl = P()
-        fn = shard_map(per_chip, mesh=self.mesh,
-                       in_specs=(repl, repl, repl, repl),
-                       out_specs=(repl, repl),
-                       check_vma=False)
+        fn = jax.shard_map(per_chip, mesh=self.mesh,
+                           in_specs=(repl, repl, repl, repl),
+                           out_specs=(repl, repl),
+                           check_vma=False)
         ys, new_states = fn(params, states, xs, rng)
         return ys.reshape((b,) + ys.shape[2:]), new_states
 

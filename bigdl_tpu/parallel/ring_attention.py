@@ -31,7 +31,6 @@ from jax import lax
 _NEG_INF = -1e30
 
 
-from bigdl_tpu.parallel.mesh import mark_varying as _mark_varying
 from bigdl_tpu.parallel.mesh import ring_perm
 
 
@@ -102,7 +101,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     # mark the accumulators device-varying over the ring axis so the scan
     # carry types line up with the (varying) k/v shards
-    num0, m0, l0 = (_mark_varying(t, axis_name) for t in (num0, m0, l0))
+    num0, m0, l0 = (
+        jax.lax.pcast(t, axis_name, to="varying") for t in (num0, m0, l0))
     (k_f, v_f, num, m, l), _ = lax.scan(
         step, (k, v, num0, m0, l0), jnp.arange(n)
     )
@@ -116,10 +116,9 @@ def make_ring_attention(mesh, axis_name: str, causal: bool = False):
     Returns a function (q, k, v) -> out operating on GLOBAL arrays whose
     sequence dim (axis 2) is sharded over ``axis_name``.
     """
-    from bigdl_tpu.parallel._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, None, axis_name, None)
     fn = functools.partial(ring_attention, axis_name=axis_name, causal=causal)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

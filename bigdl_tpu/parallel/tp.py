@@ -36,7 +36,6 @@ from bigdl_tpu.parallel.mesh import (
     UNCONSTRAINED,
     axis_size,
     constrain,
-    current_mesh,
 )
 
 
@@ -147,17 +146,12 @@ class TensorParallelAttention(Module):
         q = self._heads(self.run_child(ctx, "q", x))
         k = self._heads(self.run_child(ctx, "k", x))
         v = self._heads(self.run_child(ctx, "v", x))
-        # Under an active mesh the heads/sequence dims are sharded; the
-        # Pallas flash kernel is a Mosaic custom call with no GSPMD
-        # partitioning rule, so force the XLA einsum path there (XLA
-        # partitions it and inserts the collectives). Single-chip keeps the
-        # auto-selected flash kernel.
-        use_flash = False if current_mesh() is not None else None
+        # under an active mesh the dispatcher takes the XLA einsum path
+        # (ops/attention.py:_kernel_platform); single-chip keeps flash
         o = dot_product_attention(
             q, k, v, bias=bias, causal=causal,
             dropout_rate=self.attention_dropout if ctx.training else 0.0,
             dropout_rng=ctx.rng() if (ctx.training and self.attention_dropout) else None,
-            use_flash=use_flash,
         )
         o = constrain(o, UNCONSTRAINED, self.axis, self.sp_axis or UNCONSTRAINED,
                       UNCONSTRAINED)

@@ -52,10 +52,9 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
 
 def make_ulysses_attention(mesh, axis_name: str, causal: bool = False):
     """shard_map wrapper over GLOBAL (b, h, s, d) arrays, seq sharded."""
-    from bigdl_tpu.parallel._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, None, axis_name, None)
     fn = functools.partial(ulysses_attention, axis_name=axis_name, causal=causal)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

@@ -134,6 +134,25 @@ class _TraceCounts:
         self.chunk = 0
 
 
+def _jit(fn, cache_sharding, donate: bool):
+    """``jax.jit`` with the cache (argument 1) donated. A sharded engine
+    traces under its cache's mesh, which is how attention dispatch sees
+    it: a Mosaic kernel has no GSPMD partitioning rule, so auto-selection
+    takes the XLA path there (``ops/attention.py:_kernel_platform``)."""
+    if cache_sharding is None:
+        return jax.jit(fn, donate_argnums=(1,) if donate else ())
+    from bigdl_tpu.parallel.mesh import use_mesh
+
+    mesh = (cache_sharding[0] if isinstance(cache_sharding, tuple)
+            else cache_sharding).mesh
+
+    def in_mesh(*args):
+        with use_mesh(mesh):
+            return fn(*args)
+
+    return jax.jit(in_mesh, donate_argnums=(1,) if donate else ())
+
+
 def _cache_pinner(cache_sharding):
     """Constraint applied to the new cache INSIDE every jitted kernel
     when the engine runs sharded: pins the output cache to the exact
@@ -205,9 +224,8 @@ class DecodeKernels:
             logits, cache = model.decode_step(params, cache, tokens, positions)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pin(cache)
 
-        dn = (1,) if donate else ()
-        self._prefill = jax.jit(prefill, donate_argnums=dn)
-        self._decode = jax.jit(decode, donate_argnums=dn)
+        self._prefill = _jit(prefill, cache_sharding, donate)
+        self._decode = _jit(decode, cache_sharding, donate)
 
     @property
     def prefill_traces(self) -> int:
@@ -286,10 +304,9 @@ class PagedDecodeKernels:
                                            keys, bias)
             return toks, new_keys, pin(cache)
 
-        dn = (1,) if donate else ()
-        self._prefill = jax.jit(prefill, donate_argnums=dn)
-        self._chunk = jax.jit(chunk, donate_argnums=dn)
-        self._decode = jax.jit(decode, donate_argnums=dn)
+        self._prefill = _jit(prefill, cache_sharding, donate)
+        self._chunk = _jit(chunk, cache_sharding, donate)
+        self._decode = _jit(decode, cache_sharding, donate)
 
     @property
     def prefill_traces(self) -> int:
@@ -465,12 +482,11 @@ class SpeculativeKernels:
                 temps, top_ks, top_ps, keys, out_base)
             return n_acc, out, pin(cache)
 
-        dn = (1,) if donate else ()
-        self._prefill = jax.jit(prefill, donate_argnums=dn)
-        self._chunk = jax.jit(chunk, donate_argnums=dn)
-        self._draft_write = jax.jit(draft_write, donate_argnums=dn)
-        self._draft = jax.jit(draft, donate_argnums=dn)
-        self._verify = jax.jit(verify, donate_argnums=dn)
+        self._prefill = _jit(prefill, cache_sharding, donate)
+        self._chunk = _jit(chunk, cache_sharding, donate)
+        self._draft_write = _jit(draft_write, cache_sharding, donate)
+        self._draft = _jit(draft, cache_sharding, donate)
+        self._verify = _jit(verify, cache_sharding, donate)
 
     # trace counters (compile-once assertions read these)
     @property

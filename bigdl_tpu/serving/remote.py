@@ -1003,7 +1003,13 @@ def start_replica_process(factory: str, *, host: str = "127.0.0.1",
     return the connected-on-demand :class:`RemoteReplica` that OWNS the
     child (``close`` reaps it, ``revive`` relaunches it). ``factory``
     is a ``module:function`` path resolving to a zero-arg callable that
-    builds the backend INSIDE the child — nothing is pickled."""
+    builds the backend INSIDE the child — nothing is pickled.
+
+    A TPU chip belongs to one process: a parent that has touched JAX
+    holds it, and a child whose factory needs the same chip fails (or
+    waits on the device lock) until ``startup_timeout`` kills it and
+    this raises :class:`TransportError`. Give each child its own chip
+    through ``env=``, or a CPU backend (``env={"JAX_PLATFORMS": "cpu"}``)."""
     launch = {"factory": factory, "host": host, "env": env,
               "startup_timeout": startup_timeout}
     proc, addr = _spawn_replica(**launch)

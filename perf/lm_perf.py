@@ -7,7 +7,7 @@ chip with the repo's scan-based LSTM stack: batch of 20-token windows,
 full fwd+bwd+Adagrad step, differential timing (same scheme as
 bench.py).
 
-Usage: python perf/lm_perf.py   (appends to perf/artifacts/r4_measurements.txt manually)
+Usage: python perf/lm_perf.py
 """
 import json
 import os
@@ -19,10 +19,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-_p = os.environ.get("JAX_PLATFORMS")
-if _p:
-    jax.config.update("jax_platforms", _p)
 
 
 def main():

@@ -20,7 +20,7 @@ For each configuration that compiles, the final schedule is scanned for
 (fusion/convolution/dot) instructions placed inside each window — >0
 means the collective is genuinely overlapped with backward compute.
 
-Appends an "async attempt" section to perf/artifacts/overlap_hlo_summary.txt.
+Appends an "async attempt" section to perf/artifacts/overlap_async.txt.
 """
 import os
 import sys

@@ -258,6 +258,10 @@ class _TrackedRLock(_TrackedLock):
     def _is_owned(self) -> bool:
         return self._inner._is_owned()
 
+    def _recursion_count(self) -> int:
+        # multiprocessing.resource_tracker asks its RLock this (3.12.x)
+        return self._inner._recursion_count()
+
     def _release_save(self):
         count = self._graph.note_release_all(self._serial)
         return (self._inner._release_save(), count)

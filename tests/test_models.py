@@ -95,6 +95,20 @@ def test_graft_entry_contract():
     mod.dryrun_multichip(4)
 
 
+def test_graft_entry_refuses_a_shortfall_of_devices():
+    """Asked for more devices than JAX sees, the dry run raises — it never
+    re-runs itself on virtual CPU devices and reports ok."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parent.parent / "__graft_entry__.py"
+    spec = importlib.util.spec_from_file_location("__graft_entry__", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with pytest.raises(RuntimeError, match="found 8 cpu device"):
+        mod.dryrun_multichip(16)
+
+
 def test_resnet_nhwc_matches_nchw():
     """The TPU-preferred channels-last ResNet computes the same function
     as the NCHW build given transposed input and identical params (the

@@ -75,7 +75,9 @@ def ref_greedy(model, params, prompt, n):
     ids = [int(t) for t in prompt]
     out = []
     for _ in range(n):
-        logits, _ = model.apply(params, jnp.asarray([ids]))
+        # jitted per length: op-by-op dispatch compiles every primitive
+        # anew at every new length and was the slowest thing in tier-1
+        logits, _ = jax.jit(model.apply)(params, jnp.asarray([ids]))
         tok = int(np.asarray(logits)[0, -1].argmax())
         ids.append(tok)
         out.append(tok)

@@ -88,7 +88,8 @@ def ref_greedy(model, params, prompt, n):
     ids = [int(t) for t in prompt]
     out = []
     for _ in range(n):
-        logits, _ = model.apply(params, jnp.asarray([ids]))
+        # jitted per length (see test_paged_generation.ref_greedy)
+        logits, _ = jax.jit(model.apply)(params, jnp.asarray([ids]))
         tok = int(np.asarray(logits)[0, -1].argmax())
         ids.append(tok)
         out.append(tok)
